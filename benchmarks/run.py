@@ -31,6 +31,8 @@ def main(argv=None) -> None:
                          "collective counts into the JSON artifact")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
     from . import kernels_bench, paper_figs
     benches = list(kernels_bench.ALL)
     if os.environ.get("REPRO_BENCH_FAST") != "1":

@@ -27,22 +27,6 @@ from .distances import gathered_dot, query_key_fn
 from .filters import AttrTable, FilterBatch
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it at the top level with ``check_vma``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` whose equivalent kwarg is
-    ``check_rep``.
-    """
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        return top(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as exp
-    return exp(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 @dataclasses.dataclass(frozen=True)
 class ShardedServeConfig:
     k: int = 10
@@ -153,7 +137,7 @@ def make_serve_step(mesh: Mesh, cfg: ShardedServeConfig, attr_kind: str,
                 shard_spec, q_spec, q_spec]
     if variant in ("int8", "int8_reg"):
         in_specs.append(P())        # replicated dequant scale
-    return _shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(q_spec, q_spec, q_spec),
@@ -178,6 +162,6 @@ def make_build_step(mesh: Mesh, build_cfg, attr_kind: str, n_bits: int = 0):
         return g[None], d[None]
 
     spec = P(sx)
-    return _shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(spec,) * 7, out_specs=(spec, spec), check_vma=False)

@@ -7,7 +7,9 @@ query planner (serve/planner.py) routes low-selectivity batches here, and
 the executor (serve/executor.py) adapts the result to the SearchResult
 contract. ``use_kernel=True`` swaps the per-block distance matmul for the
 scalar-prefetch Pallas tile scan (kernels/ops.gather_dist_tile, padded once
-up front) so each database block is DMA'd HBM->VMEM once on TPU.
+up front) so each database block is DMA'd HBM->VMEM once on TPU. The block
+defaults to ``kernels.gather_dist.scan_tile(d)``: 4096 rows up to d=256,
+fewer at wider rows, so a double-buffered tile fits the chip's VMEM.
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels.gather_dist import scan_tile
 from .distances import INF, sq_norms
 from .filters import AttrTable, matches_rows
 
@@ -30,7 +33,7 @@ class GroundTruth(NamedTuple):
 
 @partial(jax.jit, static_argnames=("k", "block", "use_kernel"))
 def exact_filtered_knn(xb, attr: AttrTable, queries, filt,
-                       k: int = 10, block: int = 4096,
+                       k: int = 10, block: int | None = None,
                        use_kernel: bool = False) -> GroundTruth:
     """Exact top-k among filter-satisfying points, blocked scan.
 
@@ -42,6 +45,7 @@ def exact_filtered_knn(xb, attr: AttrTable, queries, filt,
     """
     N, d = xb.shape
     B = queries.shape[0]
+    block = block or scan_tile(d)
     xb32 = xb.astype(jnp.float32)
     xn = sq_norms(xb32)
     q32 = queries.astype(jnp.float32)
@@ -71,7 +75,8 @@ def exact_filtered_knn(xb, attr: AttrTable, queries, filt,
         else:
             xbl = jnp.take(xb32, idc, axis=0)                # [blk, d]
             d2 = (jnp.take(xn, idc)[None, :] + qn[:, None]
-                  - 2.0 * q32 @ xbl.T)                       # [B, blk]
+                  - 2.0 * jnp.matmul(q32, xbl.T,             # [B, blk]
+                                     precision=jax.lax.Precision.HIGHEST))
         # gather the block's [block] attr rows ONCE and broadcast against
         # the filter batch — the old [B, block] id matrix repeated the same
         # gather B times per block on the prefilter hot path

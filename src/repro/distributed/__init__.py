@@ -2,4 +2,4 @@
 fault tolerance."""
 from .sharding import (Rules, current_rules, logical_constraint, make_rules,
                        put_db_sharded, resolve_spec, serve_mesh,
-                       tree_shardings, use_rules)
+                       stack_shards, tree_shardings, use_rules)

@@ -15,6 +15,7 @@ import threading
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -134,20 +135,47 @@ def serve_mesh(n_shards: int) -> Mesh:
     return Mesh(np.asarray(devs[:n_shards]), ("data",))
 
 
-def put_db_sharded(tree, rules: Rules):
-    """Place stacked per-shard arrays ([S, ...] leaves) on the mesh with
-    the leading dim bound to the ``db_shard`` rule (-> the "data" axis).
+def put_db_sharded(tree, mesh: Mesh):
+    """Place stacked per-shard arrays ([S, ...] leaves) on ``mesh`` with the
+    leading dim split over the "data" axis; trailing dims stay whole.
 
-    One ``jax.device_put`` per leaf; trailing dims stay replicated. The
-    divisibility guard in :func:`resolve_spec` applies — a leading dim not
-    divisible by the data-axis size falls back to replication rather than
-    erroring, matching every other rule-resolved placement.
+    One ``jax.device_put`` per leaf (a no-op for a leaf already placed so).
+    There is no replication fallback: a leading dim that is not the
+    data-axis size is an error, never a silent copy of every shard onto
+    every device.
     """
+    S = int(mesh.shape["data"])
+
     def one(x):
-        spec = resolve_spec(("db_shard",) + (None,) * (x.ndim - 1),
-                            x.shape, rules)
-        return jax.device_put(x, NamedSharding(rules.mesh, spec))
+        if x.shape[0] != S:
+            raise ValueError(f"stacked leaf {x.shape} does not carry one "
+                             f"row block per shard of the {S}-way mesh")
+        return jax.device_put(x, NamedSharding(mesh, P("data")))
     return jax.tree.map(one, tree)
+
+
+def stack_shards(trees: Sequence, mesh: Mesh):
+    """Per-shard pytrees (shard s's leaves ``[n_loc, ...]``) -> stacked
+    ``[S, n_loc, ...]`` arrays split over the mesh's "data" axis.
+
+    Each global array is assembled from single-device pieces
+    (``jax.make_array_from_single_device_arrays``): shard s's leaf moves to
+    the s-th mesh device if it is not already there, and no device ever
+    holds another shard's rows — there is no stack on one device first.
+    """
+    devs = list(mesh.devices.flat)
+    if len(trees) != len(devs):
+        raise ValueError(f"{len(trees)} shards for a {len(devs)}-device "
+                         f"mesh")
+    sharding = NamedSharding(mesh, P("data"))
+
+    def assemble(*leaves):
+        pieces = [jax.device_put(jnp.expand_dims(x, 0), d)
+                  for x, d in zip(leaves, devs)]
+        shape = (len(pieces),) + tuple(pieces[0].shape[1:])
+        return jax.make_array_from_single_device_arrays(shape, sharding,
+                                                        pieces)
+    return jax.tree.map(assemble, *trees)
 
 
 def logical_constraint(x, axes: Sequence[Optional[str]]):

@@ -1,9 +1,15 @@
 """Packed-bitset attribute distance Pallas kernels (popcount on the VPU).
 
-Subset/boolean attribute & filter distances over uint32-packed bitsets
-(DESIGN.md §2): XOR/ANDN + ``lax.population_count`` on (bq, W)x(bn, W)
-VMEM tiles, producing the [B, N] distance matrices used by the subset
-dist_F (|f \\ a|), the Hamming dist_A, and the pre-filter validity scans.
+Subset/boolean attribute & filter distances over uint32-packed bitsets:
+XOR/ANDN + ``lax.population_count`` on (bq, bn) VMEM tiles, producing the
+[B, N] distance matrices used by the subset dist_F (|f \\ a|), the Hamming
+dist_A, and the pre-filter validity scans.
+
+The word axis is the innermost grid axis: each step brings one word of the
+bq filter rows (a ``(bq, 1)`` column) and of the bn attribute rows (a
+``(1, bn)`` row) into VMEM and adds the popcount of their (bq, bn)
+broadcast into the resident output tile. Compile time and VMEM therefore
+do not grow with W — the boolean kind's truth tables run to 1,024 words.
 """
 from __future__ import annotations
 
@@ -14,23 +20,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _popc(x):
-    return jax.lax.population_count(x)
-
-
 def _make_kernel(op: str):
     def kernel(a_ref, b_ref, o_ref):
-        a = a_ref[...]                                    # [bq, W]
-        b = b_ref[...]                                    # [bn, W]
-        acc = jnp.zeros((a.shape[0], b.shape[0]), jnp.int32)
-        W = a.shape[1]
-        for w in range(W):  # unrolled: W is small (<= 64 words)
-            if op == "xor":
-                x = a[:, w][:, None] ^ b[:, w][None, :]
-            else:  # "deficit": f & ~a
-                x = a[:, w][:, None] & ~b[:, w][None, :]
-            acc = acc + _popc(x).astype(jnp.int32)
-        o_ref[...] = acc
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            o_ref[...] = jnp.zeros(o_ref.shape, jnp.int32)
+
+        a = a_ref[...]                                    # [bq, 1]
+        b = b_ref[...]                                    # [1, bn]
+        x = (a ^ b) if op == "xor" else (a & ~b)  # "deficit": f & ~a
+        o_ref[...] += jax.lax.population_count(x).astype(jnp.int32)
     return kernel
 
 
@@ -50,12 +49,12 @@ def bitset_dist(a: jnp.ndarray, b: jnp.ndarray, *, op: str = "xor",
     assert B % bq == 0 and N % bn == 0, (B, N, bq, bn)
     return pl.pallas_call(
         _make_kernel(op),
-        grid=(B // bq, N // bn),
+        grid=(B // bq, N // bn, W),
         in_specs=[
-            pl.BlockSpec((bq, W), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, W), lambda i, j: (j, 0)),
+            pl.BlockSpec((None, bq, 1), lambda i, j, w: (w, i, 0)),
+            pl.BlockSpec((None, 1, bn), lambda i, j, w: (w, 0, j)),
         ],
-        out_specs=pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((bq, bn), lambda i, j, w: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.int32),
         interpret=interpret,
-    )(a, b)
+    )(a.T[:, :, None], b.T[:, None, :])

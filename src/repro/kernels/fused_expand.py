@@ -37,11 +37,10 @@ def _row_kernel(d, C, ids_ref, qn_ref, x_ref, q_ref, o_dist, o_attr):
     g = pl.program_id(0)
     row = x_ref[...]                                   # [1, d + 1 + A]
     vec = row[:, :d].astype(jnp.float32)               # [1, d]
-    norm = row[0, d]
+    norm = row[:, d:d + 1]                             # [1, 1]
     q = q_ref[...].astype(jnp.float32)                 # [1, d]
-    dot = jnp.sum(vec * q)
-    d2 = jnp.maximum(norm - 2.0 * dot + qn_ref[g // C], 0.0)
-    o_dist[...] = d2.reshape(1, 1)
+    dot = jnp.sum(vec * q, axis=-1, keepdims=True)     # [1, 1]
+    o_dist[...] = jnp.maximum(norm - 2.0 * dot + qn_ref[g // C], 0.0)
     o_attr[...] = row[:, d + 1:]                       # bit-preserving copy
 
 
@@ -59,24 +58,28 @@ def fused_expand(packed: jnp.ndarray, ids: jnp.ndarray, q: jnp.ndarray,
     flat = ids.reshape(-1)
     total = flat.shape[0]
 
+    # single rows ride as [rows, 1, width] with the row axis squeezed out
+    # of each block, so every block's last two dims equal the array's
     dist, attrs = pl.pallas_call(
         functools.partial(_row_kernel, d, C),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(total,),
             in_specs=[
-                pl.BlockSpec((1, row_w), lambda g, ids, qn: (ids[g], 0)),
-                pl.BlockSpec((1, d), lambda g, ids, qn: (g // C, 0)),
+                pl.BlockSpec((None, 1, row_w),
+                             lambda g, ids, qn: (ids[g], 0, 0)),
+                pl.BlockSpec((None, 1, d), lambda g, ids, qn: (g // C, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1), lambda g, ids, qn: (0, g)),
-                pl.BlockSpec((1, A), lambda g, ids, qn: (g, 0)),
+                pl.BlockSpec((None, 1, 1), lambda g, ids, qn: (g, 0, 0)),
+                pl.BlockSpec((None, 1, A), lambda g, ids, qn: (g, 0, 0)),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((1, total), jnp.float32),
-            jax.ShapeDtypeStruct((total, A), jnp.float32),
+            jax.ShapeDtypeStruct((total, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((total, 1, A), jnp.float32),
         ],
         interpret=interpret,
-    )(flat, jnp.asarray(q_norm, jnp.float32), packed, q)
+    )(flat, jnp.asarray(q_norm, jnp.float32), packed.reshape(N, 1, row_w),
+      q.reshape(B, 1, d))
     return dist.reshape(B, C), attrs.reshape(B, C, A)

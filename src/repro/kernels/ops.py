@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in ``interpret=True`` mode; on TPU the
-same calls compile to Mosaic. ``interpret`` auto-detects from the default
-backend, overridable via argument or ``repro_force_interpret()``. Wrappers
-pad inputs to tile multiples and slice results back.
+Off the chip kernels run in Pallas ``interpret=True`` mode; on a TPU backend
+every call compiles to Mosaic, whatever the caller asks for — nothing on the
+serving path can fall back to the interpreter there. Wrappers pad inputs to
+tile multiples and slice results back.
 """
 from __future__ import annotations
 
@@ -15,20 +15,12 @@ from . import fused_expand as _fe
 from . import gather_dist as _gd
 from . import l2dist as _l2
 
-_FORCE_INTERPRET: bool | None = None
-
-
-def repro_force_interpret(v: bool | None) -> None:
-    global _FORCE_INTERPRET
-    _FORCE_INTERPRET = v
-
 
 def _interp(explicit: bool | None) -> bool:
-    if explicit is not None:
-        return explicit
-    if _FORCE_INTERPRET is not None:
-        return _FORCE_INTERPRET
-    return jax.default_backend() != "tpu"
+    """False on a TPU backend; elsewhere ``explicit``, defaulting to True."""
+    if jax.default_backend() == "tpu":
+        return False
+    return True if explicit is None else explicit
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int, value=0):

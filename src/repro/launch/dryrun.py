@@ -28,12 +28,12 @@ import jax
 
 from ..configs import all_archs, make_cell
 from ..distributed.sharding import use_rules
-from .mesh import HW, make_production_mesh, set_mesh
+from .mesh import HW, make_production_mesh
 from . import roofline as RL
 
 
 def _compile(cell, mesh):
-    with set_mesh(mesh), use_rules(cell["rules"]):
+    with jax.set_mesh(mesh), use_rules(cell["rules"]):
         jitted = jax.jit(cell["fn"],
                          in_shardings=cell["in_shardings"],
                          out_shardings=cell["out_shardings"],

@@ -9,33 +9,11 @@ from __future__ import annotations
 import jax
 
 
-def mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types`` kwarg for ``jax.make_mesh``, feature-gated.
-
-    ``jax.sharding.AxisType`` only exists on newer jax; older releases (e.g.
-    the 0.4.x on this container) default every axis to Auto anyway, so
-    omitting the kwarg there is behaviour-identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def set_mesh(mesh):
-    """Context manager entering ``mesh``, across jax versions.
-
-    Newer jax has ``jax.set_mesh``; on 0.4.x the ``Mesh`` object itself is
-    the context manager that scopes named-axis resolution.
-    """
-    fn = getattr(jax, "set_mesh", None)
-    return fn(mesh) if fn is not None else mesh
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model: int = 1):
@@ -43,7 +21,7 @@ def make_local_mesh(model: int = 1):
     n = len(jax.devices())
     assert n % model == 0
     return jax.make_mesh((n // model, model), ("data", "model"),
-                         **mesh_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 HW = dict(  # TPU v5e per-chip constants used by the roofline

@@ -51,6 +51,7 @@ from ..core.distances import INF, query_key_fn, unfiltered_key_fn
 from ..core.filters import FilterExpr, matches, n_leaves
 from ..core.ground_truth import exact_filtered_knn
 from ..core.quantized import make_int8_dist_fn, rerank_exact
+from ..kernels.gather_dist import scan_tile
 from .engine import FusedEngine, make_fetch_fn
 
 LAYOUTS = ("default", "fused")
@@ -290,7 +291,7 @@ class Executor:
 
     # -- prefilter route (masked exact scan) -------------------------------
     def _scan(self, key: Tuple, xb, attr, queries, filt, *,
-              k: int, block: int, use_kernel: bool,
+              k: int, block: int | None, use_kernel: bool,
               offset: int = 0) -> SearchResult:
         """Exact masked scan adapted to the SearchResult contract — the one
         adapter behind both scan routes (prefilter over the base rows,
@@ -346,12 +347,14 @@ class Executor:
         return reorder_clauses(filt, list(v.reshape(v.shape[0], -1)))
 
     def prefilter(self, queries, filt, *, k: int,
-                  block: int = 4096, use_kernel: bool | None = None
+                  block: int | None = None, use_kernel: bool | None = None
                   ) -> SearchResult:
         """Masked exact scan over the index's (graph-segment) rows.
 
         ``use_kernel`` defaults by backend (the Pallas tile scan on TPU,
         the XLA matmul scan elsewhere), matching the kernels convention.
+        ``block`` defaults to the widest tile the chip's VMEM holds at the
+        index's d (``kernels.gather_dist.scan_tile``).
         Compound expressions are clause-reordered (cheapest most-selective
         clause first) before the scan compiles.
         """
@@ -366,7 +369,7 @@ class Executor:
 
     # -- delta route (streaming: exact scan over the live delta segment) ---
     def delta(self, queries, filt, *, k: int,
-              block: int = 4096, use_kernel: bool | None = None
+              block: int | None = None, use_kernel: bool | None = None
               ) -> SearchResult:
         """Exact masked scan over the index's delta segment, ids offset.
 
@@ -388,7 +391,8 @@ class Executor:
         xv, dattr, offset = self.index.delta_arrays()
         # the scan pads to whole blocks — cap at the (small) delta row count
         # so a 60-row delta never pays a 4096-wide distance matrix
-        block = max(1, min(block, int(xv.shape[0])))
+        block = max(1, min(block or scan_tile(int(xv.shape[1])),
+                           int(xv.shape[0])))
         key = ("delta", "default", "f32", k, 0, 0, filt.kind, block,
                use_kernel, offset)
         return self._scan(key, xv, dattr, queries, filt, k=k, block=block,

@@ -55,7 +55,8 @@ cross-host dispatch.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from concurrent.futures import ThreadPoolExecutor
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -64,11 +65,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.beam_search import SearchResult, greedy_search
 from ..core.distances import INF, query_key_fn, unfiltered_key_fn
-from ..core.distributed import _shard_map
 from ..core.filters import AttrTable, as_filter
 from ..core.ground_truth import exact_filtered_knn
 from ..core.jag import JAGConfig, JAGIndex
-from ..distributed.sharding import make_rules, put_db_sharded, serve_mesh
+from ..distributed.sharding import put_db_sharded, serve_mesh, stack_shards
 from .executor import Executor
 from .dispatch import fold_topk
 
@@ -162,13 +162,14 @@ class ShardedExecutor(Executor):
                 gids = jnp.where(res.ids >= 0, res.ids + sid * n_loc, -1)
                 return _merge_across_shards(res._replace(ids=gids), k=k,
                                             n_shards=S)
-            return _shard_map(
+            return jax.shard_map(
                 shard_fn, mesh=mesh,
                 in_specs=(P("data"),) * len(db_args) + (P(), P()),
                 out_specs=P(), check_vma=False)
         return self.run(key, make, *db_args, jnp.asarray(queries), filt)
 
-    def prefilter(self, queries, filt, *, k: int, block: int = 4096,
+    def prefilter(self, queries, filt, *, k: int,
+                  block: Optional[int] = None,
                   use_kernel: Optional[bool] = None) -> SearchResult:
         """Sharded masked exact scan: each shard scans its rows, the merge
         is exact — bit-identical to the single-device union scan."""
@@ -274,8 +275,8 @@ class ShardedExecutor(Executor):
 class ShardedJAGIndex:
     """Row-sharded JAG behind the single-device ``search_auto`` surface.
 
-    Holds the per-shard state STACKED on a leading shard axis and placed
-    on the mesh by the ``db_shard`` sharding rule:
+    Holds the per-shard state STACKED on a leading shard axis split over
+    the mesh's "data" axis — device s holds shard s's rows and no others:
 
         graph     int32 [S, N_loc, R]   shard-local neighbor ids
         xb        f32   [S, N_loc, d]
@@ -299,18 +300,14 @@ class ShardedJAGIndex:
             raise ValueError(f"mesh needs a 'data' axis, got "
                              f"{mesh.axis_names}")
         self.mesh = mesh
-        self.rules = make_rules(mesh)
         self.n_shards = int(mesh.shape["data"])
         if int(graph.shape[0]) != self.n_shards:
             raise ValueError(
                 f"stacked arrays carry {int(graph.shape[0])} shards but "
                 f"the mesh 'data' axis is {self.n_shards}-way")
         placed = put_db_sharded(
-            dict(graph=jnp.asarray(graph), xb=jnp.asarray(xb),
-                 xb_norm=jnp.asarray(xb_norm),
-                 attr_data={k: jnp.asarray(v)
-                            for k, v in attr_data.items()},
-                 entry=jnp.asarray(entry)), self.rules)
+            dict(graph=graph, xb=xb, xb_norm=xb_norm,
+                 attr_data=dict(attr_data), entry=entry), mesh)
         self.graph = placed["graph"]
         self.xb = placed["xb"]
         self.xb_norm = placed["xb_norm"]
@@ -334,7 +331,10 @@ class ShardedJAGIndex:
     def from_shards(cls, shards: Sequence[JAGIndex],
                     mesh: Optional[Mesh] = None) -> "ShardedJAGIndex":
         """Adopt per-shard JAGIndexes (equal row counts and attr kinds);
-        shard i serves global ids [i*N_loc, (i+1)*N_loc)."""
+        shard i serves global ids [i*N_loc, (i+1)*N_loc) from the i-th mesh
+        device. The stacked arrays are assembled from per-device pieces
+        (``distributed.sharding.stack_shards``): a shard built on its own
+        device stays there."""
         if not shards:
             raise ValueError("need at least one shard")
         n_loc = int(shards[0].xb.shape[0])
@@ -346,20 +346,19 @@ class ShardedJAGIndex:
             if s.attr.kind != kind or s.attr.n_bits != n_bits:
                 raise ValueError("all shards must share one attr schema")
         mesh = mesh or serve_mesh(len(shards))
+        # the planner's probe table, on the default device; gathered via the
+        # host because the shards' pieces sit on different devices
         union = AttrTable(
             kind,
-            {k: jnp.concatenate([s.attr.data[k] for s in shards], axis=0)
+            {k: jnp.asarray(np.concatenate(
+                [np.asarray(s.attr.data[k]) for s in shards], axis=0))
              for k in shards[0].attr.data},
             n_bits=n_bits)
-        return cls(
-            mesh=mesh,
-            graph=jnp.stack([s.graph for s in shards]),
-            xb=jnp.stack([s.xb for s in shards]),
-            xb_norm=jnp.stack([s.xb_norm for s in shards]),
-            attr_data={k: jnp.stack([s.attr.data[k] for s in shards])
-                       for k in shards[0].attr.data},
-            entry=jnp.stack([s.entry for s in shards]),
-            attr=union, cfg=shards[0].cfg)
+        st = stack_shards(
+            [dict(graph=s.graph, xb=s.xb, xb_norm=s.xb_norm,
+                  attr_data=dict(s.attr.data), entry=s.entry)
+             for s in shards], mesh)
+        return cls(mesh=mesh, attr=union, cfg=shards[0].cfg, **st)
 
     @classmethod
     def build(cls, xb, attr: AttrTable, cfg: JAGConfig = JAGConfig(),
@@ -367,26 +366,38 @@ class ShardedJAGIndex:
               verbose: bool = False) -> "ShardedJAGIndex":
         """Split rows contiguously into S shards and build one sub-graph
         per shard (shard-local entry seeds included). N must divide by S —
-        ragged resharding is a cross-host-dispatch follow-on."""
+        ragged resharding is a cross-host-dispatch follow-on.
+
+        Shard s is built on the s-th mesh device, from rows copied there
+        from the host; the S builds run concurrently, one thread each, so
+        every device works on its own shard at once."""
         if mesh is None:
             if n_shards is None:
                 raise ValueError("pass n_shards or a mesh")
             mesh = serve_mesh(int(n_shards))
-        S = int(mesh.shape["data"])
-        xb = jnp.asarray(xb)
+        devs = list(mesh.devices.flat)
+        S = len(devs)
+        xb = np.asarray(xb)
         n = int(xb.shape[0])
         if n % S != 0:
             raise ValueError(f"N={n} rows do not split evenly into "
                              f"{S} shards")
         n_loc = n // S
-        shards: List[JAGIndex] = []
-        for s in range(S):
+        host_attr = {k: np.asarray(v) for k, v in attr.data.items()}
+
+        def build_one(s: int) -> JAGIndex:
             lo, hi = s * n_loc, (s + 1) * n_loc
-            sub = AttrTable(attr.kind,
-                            {k: v[lo:hi] for k, v in attr.data.items()},
-                            n_bits=attr.n_bits)
-            shards.append(JAGIndex.build(xb[lo:hi], sub, cfg,
-                                         verbose=verbose))
+            dev = devs[s]
+            with jax.default_device(dev):
+                sub = AttrTable(attr.kind,
+                                {k: jax.device_put(v[lo:hi], dev)
+                                 for k, v in host_attr.items()},
+                                n_bits=attr.n_bits)
+                return JAGIndex.build(jax.device_put(xb[lo:hi], dev), sub,
+                                      cfg, verbose=verbose)
+
+        with ThreadPoolExecutor(max_workers=S) as pool:
+            shards = list(pool.map(build_one, range(S)))
         return cls.from_shards(shards, mesh=mesh)
 
     # -- serving (the JAGIndex surface) ------------------------------------

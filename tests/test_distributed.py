@@ -53,8 +53,8 @@ import sys; sys.path.insert(0, "src")
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import JAGConfig, JAGIndex, range_table
 from repro.core.distributed import make_serve_step, ShardedServeConfig
-from repro.launch.mesh import mesh_kwargs, set_mesh
-mesh = jax.make_mesh((4, 2), ("data", "model"), **mesh_kwargs(2))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 S, Nloc, d = 8, 300, 8
 xb = rng.normal(size=(S, Nloc, d)).astype(np.float32)
@@ -72,7 +72,7 @@ q = rng.normal(size=(B, d)).astype(np.float32)
 lo = rng.uniform(0, 90, B).astype(np.float32)
 step = jax.jit(make_serve_step(mesh, ShardedServeConfig(k=5, ls=24,
     max_iters=48, query_chunk=8), "range", "range"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     ids, prim, sec = step(jnp.asarray(graphs), jnp.asarray(xb),
         jnp.asarray(xbn), {"value": jnp.asarray(vals)},
         jnp.asarray(entries), jnp.asarray(q),
@@ -109,9 +109,8 @@ def test_int8_reg_dist_batch_invariance():
     from repro.core import JAGConfig, JAGIndex, range_table
     from repro.core.distributed import ShardedServeConfig, make_serve_step
     from repro.core.quantized import quantize_int8
-    from repro.launch.mesh import mesh_kwargs, set_mesh
-
-    mesh = jax.make_mesh((1, 1), ("data", "model"), **mesh_kwargs(2))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rng = np.random.default_rng(3)
     n, d, B = 240, 8, 16
     xb = rng.normal(size=(n, d)).astype(np.float32)
@@ -131,7 +130,7 @@ def test_int8_reg_dist_batch_invariance():
             {"lo": jnp.asarray(lo), "hi": jnp.asarray(lo + 10)},
             jnp.asarray(scale))
     outs = []
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for chunk in (16, 8):  # 1x16 vs 2x8: different GEMM batch sizes
             step = jax.jit(make_serve_step(
                 mesh, ShardedServeConfig(k=5, ls=24, max_iters=48,

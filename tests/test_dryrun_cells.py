@@ -15,14 +15,14 @@ import sys; sys.path.insert(0, "src")
 import jax
 from repro.configs import make_cell
 from repro.distributed.sharding import use_rules
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 cells = [("fm", "retrieval_cand"), ("gcn-cora", "molecule"),
          ("qwen3-1.7b", "decode_32k"), ("jag", "serve_1b")]
 for mp in (False, True):
     mesh = make_production_mesh(multi_pod=mp)
     for arch, shape in cells:
         cell = make_cell(arch, shape, mesh)
-        with set_mesh(mesh), use_rules(cell["rules"]):
+        with jax.set_mesh(mesh), use_rules(cell["rules"]):
             jax.jit(cell["fn"], in_shardings=cell["in_shardings"],
                     out_shardings=cell["out_shardings"],
                     donate_argnums=cell["donate_argnums"]).lower(

@@ -69,8 +69,8 @@ def test_elastic_cross_mesh_restore(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     tree = {"w": jnp.arange(64.0).reshape(8, 8)}
     save_pytree(tree, str(tmp_path), 1)
-    from repro.launch.mesh import mesh_kwargs
-    mesh = jax.make_mesh((1,), ("data",), **mesh_kwargs(1))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = {"w": NamedSharding(mesh, P("data", None))}
     out, _ = load_pytree(tree, str(tmp_path), 1, shardings=sh)
     assert out["w"].sharding == sh["w"]
