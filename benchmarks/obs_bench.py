@@ -22,11 +22,11 @@ over ALL queries in that cell (the estimator is honest), (b) the
 introspective graph compilation must be bit-identical in (ids, keys) to
 the standard route, and (c) serving QPS with 5% shadow sampling must
 stay >= 0.95x of shadow-off QPS. The artifact (``BENCH_quality.json``)
-embeds the fused health report; ``--traces/--shadow/--spans`` dump the
-raw windows for ``jagstat --health`` / Perfetto.
+embeds the fused health report; ``--traces/--shadow`` dump the raw
+windows for ``jagstat --health``.
 
 Usage: PYTHONPATH=src python -m benchmarks.obs_bench [--quality]
-           [--json PATH] [--traces PATH] [--shadow PATH] [--spans PATH]
+           [--json PATH] [--traces PATH] [--shadow PATH]
 Env:   REPRO_BENCH_FAST=1 -> small shapes (CI smoke).
 """
 from __future__ import annotations
@@ -186,9 +186,6 @@ def run_quality(args) -> dict:
     if args.shadow:
         n_dumped = tel.shadow.dump_jsonl(args.shadow)
         print(f"# shadow dump: {n_dumped} records -> {args.shadow}")
-    if args.spans:
-        n_ev = tel.spans.export_chrome_trace(args.spans)
-        print(f"# span dump: {n_ev} events -> {args.spans}")
 
     return {
         "fast": fast,
@@ -330,9 +327,6 @@ def main(argv=None) -> dict:
     ap.add_argument("--shadow", default=None, metavar="PATH",
                     help="--quality: dump shadow-audit records as JSONL "
                          "(jagstat --health input)")
-    ap.add_argument("--spans", default=None, metavar="PATH",
-                    help="--quality: export pipeline spans as a Chrome "
-                         "trace JSON (Perfetto-loadable)")
     args = ap.parse_args(argv)
 
     out = run_quality(args) if args.quality else run_overhead_recal(args)

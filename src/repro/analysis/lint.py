@@ -1,4 +1,4 @@
-"""Layer 1 of `jagcheck`: the repo-specific AST lint (rules JAG001–JAG005).
+"""Layer 1 of `jagcheck`: the repo-specific AST lint (rules JAG001–JAG006).
 
 Each rule mechanizes an invariant a past PR established and a later change
 could silently break:
@@ -30,7 +30,10 @@ could silently break:
           clock forever), and telemetry-object mutations (ring-buffer
           ``append``, histogram ``observe``, counter ``inc``, trace
           ``record*``) are host state that must only be touched AFTER the
-          route returns, in the dispatch/search_auto wrappers.
+          route returns, in the dispatch/search_auto wrappers. A span
+          (``span(...)``, ``recorder.span(...)``, ``TraceAnnotation(...)``)
+          inside a traced function would time tracing, not execution:
+          spans go around the compiled call.
 
 Diagnostics are ``path:line: CODE message``. The config and allowlist live
 in ``pyproject.toml`` under ``[tool.jagcheck]``; every allowlist entry
@@ -402,6 +405,7 @@ _JAG006_TIMERS = ("time.time", "time.perf_counter", "time.monotonic",
                   "time.time_ns", "time.perf_counter_ns",
                   "time.monotonic_ns", "perf_counter", "monotonic")
 _JAG006_MUTATORS = ("append", "observe", "inc", "record", "record_call")
+_JAG006_SPANS = ("span", "TraceAnnotation")
 
 
 def _jag006_chain(node: ast.AST) -> str:
@@ -455,8 +459,13 @@ def _jag006(tree: ast.AST, path: str) -> List[Finding]:
             if not isinstance(node, ast.Call) or node.lineno in seen:
                 continue
             fn = _dotted(node.func)
+            chain = fn or _jag006_chain(node.func)
             what = None
-            if fn in _JAG006_TIMERS:
+            if chain.split(".")[-1] in _JAG006_SPANS:
+                what = (f"span {chain}() inside a jit-traced function "
+                        "times tracing, not execution; open the span "
+                        "around the compiled call")
+            elif fn in _JAG006_TIMERS:
                 what = (f"{fn}() takes a host timestamp — under jit it "
                         "constant-folds at trace time; time in the "
                         "host-side wrapper around the route instead")

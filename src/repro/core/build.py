@@ -17,12 +17,14 @@ for rows awaiting a future overflow re-prune).
 from __future__ import annotations
 
 import dataclasses
+from contextlib import nullcontext
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.spans import span
 from .beam_search import greedy_search
 from .distances import (INF, build_threshold_key_fn, build_weight_key_fn,
                         dist_a, sq_norms)
@@ -284,16 +286,25 @@ def make_seeds(xb: jnp.ndarray, n_seeds: int, seed: int = 0) -> jnp.ndarray:
 
 
 def finalize_graph(graph, degree, xb, xb_norm, attr, cfg: BuildConfig):
-    """Drain the overflow backlog: re-prune every row with degree > R."""
-    reprune = jax.jit(partial(_overflow_reprune, cfg=cfg))
-    for _ in range(64):  # bounded; each pass fixes up to ov_max rows
-        over = np.flatnonzero(np.asarray(degree) > cfg.degree)
+    """Drain the overflow backlog: re-prune every row with degree > R.
+
+    Each pass reads the degrees to the host (span ``sync:finalize``); the
+    first re-prune call traces the program (``jit:reprune``)."""
+    @jax.jit
+    def reprune(graph, degree, xb, xb_norm, attr, ov):
+        return _overflow_reprune(graph, degree, xb, xb_norm, attr, ov, cfg)
+
+    for i in range(64):  # bounded; each pass fixes up to ov_max rows
+        with span("sync:finalize"):
+            over = np.flatnonzero(np.asarray(degree) > cfg.degree)
         if over.size == 0:
             break
         chunk = np.full(cfg.ov_max, -1, np.int32)
         chunk[:min(over.size, cfg.ov_max)] = over[:cfg.ov_max]
-        graph, degree = reprune(graph, degree, xb, xb_norm, attr,
-                                jnp.asarray(chunk))
+        with span("compact.reprune", rows=int(min(over.size, cfg.ov_max))), \
+                (span("jit:reprune") if i == 0 else nullcontext()):
+            graph, degree = reprune(graph, degree, xb, xb_norm, attr,
+                                    jnp.asarray(chunk))
     return graph, degree
 
 

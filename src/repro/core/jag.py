@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.spans import span
 from .beam_search import SearchResult
 from .build import BuildConfig, build_graph
 from .distances import dist_a, sq_norms
@@ -309,14 +310,6 @@ class JAGIndex:
                                       run_route)
         from ..serve.planner import (GroupPlan, PlannerConfig, plan as _plan,
                                      plan_per_query)
-        filt = as_filter(filt)
-        cfg = planner or PlannerConfig()
-        mi = max_iters or 2 * ls
-        # an explicit planner= override is an explicit routing instruction
-        # (e.g. prefilter_max_sel=1.1 forcing the exact scan everywhere) —
-        # an attached cost model must never shadow it
-        router = (None if planner is not None
-                  else self.executor.cost_router(k=k, ls=ls, filt=filt))
         tel = getattr(self, "telemetry", None)
         if tel is not None and not tel.enabled:
             tel = None
@@ -329,38 +322,44 @@ class JAGIndex:
         on_group = (None if timed is None
                     else lambda g, r, st, s: timed.append((g, r, st, s)))
         introspect = bool(getattr(tel, "introspect", False))
-        spans = getattr(tel, "spans", None)
+        rec = getattr(tel, "spans", None)
+        ex = self.executor
+        ex.n_requests += 1
 
-        def _span(name, **kw):
-            from contextlib import nullcontext
-            return nullcontext() if spans is None else spans.span(name, **kw)
-
-        with _span("search_auto", mode=mode,
-                   batch=int(np.shape(queries)[0])):
+        with span("search_auto", rec, request=ex.n_requests, mode=mode,
+                  batch=int(np.shape(queries)[0])):
+            filt = as_filter(filt)
+            cfg = planner or PlannerConfig()
+            mi = max_iters or 2 * ls
+            # an explicit planner= override is an explicit routing
+            # instruction (e.g. prefilter_max_sel=1.1 forcing the exact
+            # scan everywhere) — an attached cost model must never shadow it
+            router = (None if planner is not None
+                      else ex.cost_router(k=k, ls=ls, filt=filt))
             if mode == "per_query":
-                with _span("plan"):
-                    p = plan_per_query(filt, self.attr, cfg,
-                                       executor=self.executor, router=router)
-                res = dispatch_per_query(self.executor, queries, filt, p,
+                with span("plan", rec):
+                    p = plan_per_query(filt, self.attr, cfg, executor=ex,
+                                       router=router)
+                res = dispatch_per_query(ex, queries, filt, p,
                                          k=k, ls=ls, max_iters=mi,
                                          layout=layout, dtype=dtype,
                                          on_group=on_group,
-                                         introspect=introspect, spans=spans)
+                                         introspect=introspect, spans=rec)
                 p = p._replace(realized=tuple(
                     route_descriptor(r, layout, dtype) for r in p.routes))
             elif mode == "batch":
-                with _span("plan"):
-                    p = _plan(filt, self.attr, cfg, executor=self.executor,
+                with span("plan", rec):
+                    p = _plan(filt, self.attr, cfg, executor=ex,
                               router=router)
-                with _span(f"execute:{p.route}",
-                           queries=int(np.shape(queries)[0])):
+                with span(f"execute:{p.route}", rec,
+                          queries=int(np.shape(queries)[0])):
                     if timed is None:
-                        res = run_route(self.executor, p.route, queries,
+                        res = run_route(ex, p.route, queries,
                                         filt, k=k, ls=ls, max_iters=mi,
                                         layout=layout, dtype=dtype)
                     else:
                         t0 = time.perf_counter()
-                        out = run_route(self.executor, p.route, queries,
+                        out = run_route(ex, p.route, queries,
                                         filt, k=k, ls=ls, max_iters=mi,
                                         layout=layout, dtype=dtype,
                                         introspect=introspect)

@@ -11,44 +11,47 @@ one deliberate exception: it compiles a *separate* cache entry whose
 extra outputs are pure device counters — still zero callbacks, zero
 collectives, and bit-identical (ids, keys).
 """
-from .drift import DriftReport, detect_drift, relative_error
-from .health import HealthSLO, health_report, render_health
-from .introspect import introspection_summary, stats_to_host
-from .metrics import Counter, Histogram, MetricsRegistry
-from .recal import RecalReport, heldout_error, observations_from_traces, recalibrate
-from .shadow import (ShadowAuditor, ShadowRecord, cells_from_records,
-                     load_shadow_jsonl, sel_band, wilson_interval)
-from .spans import Span, SpanRecorder
-from .telemetry import Telemetry
-from .trace import TraceBuffer, TraceRecord, load_buffer, load_jsonl
+import importlib
 
-__all__ = [
-    "Counter",
-    "DriftReport",
-    "HealthSLO",
-    "Histogram",
-    "MetricsRegistry",
-    "RecalReport",
-    "ShadowAuditor",
-    "ShadowRecord",
-    "Span",
-    "SpanRecorder",
-    "Telemetry",
-    "TraceBuffer",
-    "TraceRecord",
-    "cells_from_records",
-    "detect_drift",
-    "health_report",
-    "heldout_error",
-    "introspection_summary",
-    "load_buffer",
-    "load_jsonl",
-    "load_shadow_jsonl",
-    "observations_from_traces",
-    "recalibrate",
-    "relative_error",
-    "render_health",
-    "sel_band",
-    "stats_to_host",
-    "wilson_interval",
-]
+# every public name and the submodule that defines it. Submodules load on
+# first use (PEP 562), so importing ``repro.obs.spans`` from the lowest
+# layers (core, serve, stream) does not pull in the modules here that
+# import those layers back.
+_HOME = {
+    "Counter": "metrics",
+    "DriftReport": "drift",
+    "HealthSLO": "health",
+    "Histogram": "metrics",
+    "MetricsRegistry": "metrics",
+    "RecalReport": "recal",
+    "ShadowAuditor": "shadow",
+    "ShadowRecord": "shadow",
+    "Span": "spans",
+    "SpanRecorder": "spans",
+    "Telemetry": "telemetry",
+    "TraceBuffer": "trace",
+    "TraceRecord": "trace",
+    "cells_from_records": "shadow",
+    "detect_drift": "drift",
+    "health_report": "health",
+    "heldout_error": "recal",
+    "introspection_summary": "introspect",
+    "load_buffer": "trace",
+    "load_jsonl": "trace",
+    "load_shadow_jsonl": "shadow",
+    "observations_from_traces": "recal",
+    "recalibrate": "recal",
+    "relative_error": "drift",
+    "render_health": "health",
+    "sel_band": "shadow",
+    "span": "spans",
+    "stats_to_host": "introspect",
+    "wilson_interval": "shadow",
+}
+__all__ = sorted(_HOME)
+
+
+def __getattr__(name):
+    if name not in _HOME:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    return getattr(importlib.import_module(f".{_HOME[name]}", __name__), name)

@@ -1,27 +1,63 @@
-"""Hierarchical span timing for the serving pipeline.
+"""Spans: the program's one timing mechanism, on the profiler's clock.
 
-A :class:`SpanRecorder` wraps the host-side stages of one search —
-plan → per-route gather → execute → scatter → merge — in nested
-``with recorder.span(name):`` blocks and keeps a bounded list of
-completed :class:`Span` records.  Timing is ``time.perf_counter`` on
-the host around the compiled calls, never inside them (rule JAG006):
-attaching spans changes nothing about the programs the executor
-compiles.
+:func:`span` wraps a host-side stage in a ``jax.profiler.TraceAnnotation``
+named ``jag.<name>``. Under ``jax.profiler.trace`` (or ``start_trace``)
+the span lands on the profiler's host plane, on the same clock as the
+device programs it launches, so a trace opened in Perfetto or TensorBoard
+shows each stage beside the device ops it caused and the idle gaps
+between them. Outside a trace an annotation costs about a microsecond, so
+spans are always emitted, whether or not telemetry is attached.
 
-``chrome_trace()`` renders the recorded spans as Chrome trace-event
-JSON (``"ph": "X"`` complete events, microsecond ``ts``/``dur``) —
-``export_chrome_trace(path)`` writes a file that loads directly in
-Perfetto / ``chrome://tracing``.  Nesting is expressed the way those
-viewers expect: same pid/tid, containment by time range; ``depth`` is
-additionally recorded in ``args`` for programmatic consumers.
+Spans sit on the host around compiled calls, never inside a traced
+function (rule JAG006): a span inside ``jax.jit`` would time tracing, not
+execution. Nesting is by time on one thread. The names in use:
+
+=====================  ==================================================
+``search_auto``        one request (``request=<n>``: the executor's count)
+``plan``               the planner; children ``plan.probe`` (sample ids,
+                       estimate launch) and ``plan.band`` (host banding)
+``gather:<route>``     one route group's query and filter gather
+``execute:<route>``    one route group's launch
+``scatter``            the regroup into query order
+``delta``, ``merge``   a streaming index's delta scan and its merge
+``sync:<site>``        a device->host read (``planner``, ``reorder``,
+                       ``finalize``)
+``jit:<program>``      the first call of a jitted function: trace,
+                       compile (or cache load) and launch
+``compact``            a streaming compaction; children
+                       ``compact.prepare``, ``compact.insert``,
+                       ``compact.finalize``, ``compact.reprune``
+=====================  ==================================================
+
+A :class:`SpanRecorder` passed to :func:`span` also keeps the span as a
+host-clock :class:`Span` record, which ``Telemetry(spans=True)`` reads.
+
+This module imports only ``jax`` and the standard library, so that every
+layer can import it.
 """
 from __future__ import annotations
 
-import json
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+PREFIX = "jag."
+
+
+@contextmanager
+def span(name: str, recorder: Optional["SpanRecorder"] = None, **args):
+    """Time a host-side stage as the profiler span ``jag.<name>``; record
+    it in ``recorder`` too when one is given. ``args`` become the trace
+    event's arguments."""
+    with TraceAnnotation(PREFIX + name, **args):
+        if recorder is None:
+            yield
+        else:
+            with recorder.span(name, **args):
+                yield
 
 
 @dataclass(frozen=True)
@@ -87,33 +123,5 @@ class SpanRecorder:
             out[s.name] = out.get(s.name, 0.0) + s.duration_us
         return out
 
-    def chrome_trace(self) -> List[dict]:
-        """The recorded spans as Chrome trace-event complete events."""
-        events = []
-        for s in self.spans:
-            args = dict(s.args)
-            args["depth"] = s.depth
-            if s.parent is not None:
-                args["parent"] = s.parent
-            events.append({
-                "name": s.name, "cat": "serve", "ph": "X",
-                "ts": round(s.t0 * 1e6, 3),
-                "dur": round(s.duration_us, 3),
-                "pid": 0, "tid": 0, "args": args,
-            })
-        return events
 
-    def export_chrome_trace(self, path: str) -> int:
-        """Write ``{"traceEvents": [...]}`` JSON; returns the event count.
-
-        The object form (rather than the bare array) keeps the file
-        self-describing; both load in Perfetto and chrome://tracing.
-        """
-        events = self.chrome_trace()
-        with open(path, "w") as fh:
-            json.dump({"traceEvents": events,
-                       "displayTimeUnit": "ms"}, fh)
-        return len(events)
-
-
-__all__ = ["Span", "SpanRecorder"]
+__all__ = ["PREFIX", "Span", "SpanRecorder", "span"]

@@ -49,9 +49,10 @@ class Telemetry:
       introspective compilation (own cache-key component, bit-identical
       results) and stamp per-query hops / saturation step / dead-end
       counters into the trace records.
-    * ``spans`` — record hierarchical pipeline spans
-      (plan → gather → execute → scatter → merge) into a
-      :class:`~repro.obs.spans.SpanRecorder` with Chrome-trace export.
+    * ``spans`` — also record the pipeline's spans
+      (plan → gather → execute → scatter → merge), which the profiler
+      trace always receives, as host-clock records in a
+      :class:`~repro.obs.spans.SpanRecorder`.
     """
 
     def __init__(self, *, capacity: int = 4096,

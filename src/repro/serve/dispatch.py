@@ -36,13 +36,13 @@ principle tile low-order float bits differently per batch size.
 from __future__ import annotations
 
 import time
-from contextlib import nullcontext
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.beam_search import SearchResult
+from ..obs.spans import span
 from .planner import PerQueryPlan
 
 __all__ = ["dispatch_per_query", "fold_topk", "merge_topk", "regroup",
@@ -162,17 +162,6 @@ def regroup(parts, groups, batch: int) -> SearchResult:
                           for f in SearchResult._fields))
 
 
-def _span(spans, name: str, **args):
-    """``spans.span(...)`` when a recorder is attached, else a no-op.
-
-    Duck-typed so this module never imports ``repro.obs`` — any object
-    with a ``span(name, **args)`` context manager works.
-    """
-    if spans is None:
-        return nullcontext()
-    return spans.span(name, **args)
-
-
 def dispatch_per_query(executor, queries, filt,
                        pq: PerQueryPlan, *, k: int, ls: int, max_iters: int,
                        layout: str = "default", dtype: str = "f32",
@@ -192,15 +181,16 @@ def dispatch_per_query(executor, queries, filt,
     never enter the compiled routes (JAG006). ``stats`` is the graph
     route's per-query ``TraversalStats`` when ``introspect=True`` (None
     otherwise). Off (None), nothing blocks and dispatch is unchanged.
-    ``spans`` is an optional ``repro.obs.SpanRecorder`` timing the
-    gather → execute → scatter stages (host-side, around the compiled
-    calls — never inside them).
+    The gather → execute → scatter stages are profiler spans
+    (``repro.obs.spans``; host-side, around the compiled calls — never
+    inside them); ``spans``, an optional ``SpanRecorder``, records them
+    too.
     """
     q = jnp.asarray(queries)
 
     def _run(group, q_g, f_g):
-        with _span(spans, f"execute:{group.route}",
-                   queries=int(np.shape(q_g)[0])):
+        with span(f"execute:{group.route}", spans,
+                  queries=int(np.shape(q_g)[0])):
             if on_group is None:
                 out = run_route(executor, group.route, q_g, f_g, k=k,
                                 ls=ls, max_iters=max_iters, layout=layout,
@@ -219,9 +209,9 @@ def dispatch_per_query(executor, queries, filt,
         return _run(pq.groups[0], q, filt)
     parts = []
     for g in pq.groups:
-        with _span(spans, f"gather:{g.route}", queries=int(g.ids.size)):
+        with span(f"gather:{g.route}", spans, queries=int(g.ids.size)):
             q_g = jnp.take(q, jnp.asarray(g.ids), axis=0)
             f_g = filt.take(g.ids)
         parts.append(_run(g, q_g, f_g))
-    with _span(spans, "scatter", batch=int(q.shape[0])):
+    with span("scatter", spans, batch=int(q.shape[0])):
         return regroup(parts, pq.groups, q.shape[0])

@@ -39,7 +39,6 @@ never route on a stale-n probe or serve from a pre-compaction layout.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Callable, Tuple
 
 import jax
@@ -52,6 +51,7 @@ from ..core.filters import FilterExpr, matches, n_leaves
 from ..core.ground_truth import exact_filtered_knn
 from ..core.quantized import make_int8_dist_fn, rerank_exact
 from ..kernels.gather_dist import scan_tile
+from ..obs.spans import span
 from .engine import FusedEngine, make_fetch_fn
 
 LAYOUTS = ("default", "fused")
@@ -81,6 +81,8 @@ class Executor:
         # epoch-driven cache eviction. Host-side only — never traced.
         self.miss_hook: Callable | None = None
         self.roll_hook: Callable | None = None
+        # search_auto calls so far: the request id its spans carry
+        self.n_requests = 0
 
     # -- cache plumbing ----------------------------------------------------
     @property
@@ -130,6 +132,9 @@ class Executor:
         invoked on a cache miss, so closure-captured statics (k, ls, ...)
         must be part of ``key``. Keys are stored under the current data
         epoch (``(epoch,) + key``); rolling the epoch evicts them all.
+        The function's name is the program's (``jit_<name>`` on the
+        device); a miss's call, which traces and compiles it, is the span
+        ``jit:<route>``.
         """
         self._roll_epoch()
         if self.trace_log is not None:
@@ -140,6 +145,8 @@ class Executor:
             if self.miss_hook is not None:
                 self.miss_hook(epoch_key)
             fn = self._cache[epoch_key] = jax.jit(make())
+            with span(f"jit:{key[0]}"):
+                return fn(*args)
         return fn(*args)
 
     def cache_keys(self, full: bool = False) -> Tuple:
@@ -212,12 +219,12 @@ class Executor:
 
         if dtype == "f32" and layout == "default":
             def make():
-                def run(graph, xb, xb_norm, attr, q, filt, entry):
+                def graph(graph, xb, xb_norm, attr, q, filt, entry):
                     return greedy_search(graph, xb, xb_norm, attr, q, entry,
                                          query_key_fn(filt), ls=ls, k=k,
                                          max_iters=max_iters,
                                          introspect=introspect)
-                return run
+                return graph
             return self.run(key, make, idx.graph, idx.xb, idx.xb_norm,
                             idx.attr, q, filt, idx.entry)
 
@@ -225,13 +232,13 @@ class Executor:
             lay = idx.fused_layout("f32")
 
             def make():
-                def run(graph, xb, xb_norm, attr, lay, q, filt, entry):
+                def graph(graph, xb, xb_norm, attr, lay, q, filt, entry):
                     return greedy_search(graph, xb, xb_norm, attr, q, entry,
                                          query_key_fn(filt), ls=ls, k=k,
                                          max_iters=max_iters,
                                          fetch_fn=make_fetch_fn(lay),
                                          introspect=introspect)
-                return run
+                return graph
             return self.run(key, make, idx.graph, idx.xb, idx.xb_norm,
                             idx.attr, lay, q, filt, idx.entry)
 
@@ -239,7 +246,7 @@ class Executor:
             lay = idx.fused_layout("int8")
 
             def make():
-                def run(graph, xb, xb_norm, attr, lay, q, filt, entry):
+                def graph(graph, xb, xb_norm, attr, lay, q, filt, entry):
                     out = greedy_search(graph, xb, xb_norm, attr, q, entry,
                                         query_key_fn(filt), ls=ls, k=ls,
                                         max_iters=max_iters,
@@ -251,15 +258,15 @@ class Executor:
                     res = SearchResult(i, p, s, res.vlog, res.n_expanded,
                                        res.n_dist)
                     return (res, stats) if introspect else res
-                return run
+                return graph
             return self.run(key, make, idx.graph, idx.xb, idx.xb_norm,
                             idx.attr, lay, q, filt, idx.entry)
 
         xq, scale, xq_norm = idx.quantized()  # int8, split layout
 
         def make():
-            def run(graph, xq, xq_norm, scale, xb, xb_norm, attr, q, filt,
-                    entry):
+            def graph(graph, xq, xq_norm, scale, xb, xb_norm, attr, q, filt,
+                      entry):
                 out = greedy_search(
                     graph, xq, xq_norm, attr, q, entry,
                     query_key_fn(filt), ls=ls, k=ls, max_iters=max_iters,
@@ -270,7 +277,7 @@ class Executor:
                 res = SearchResult(i, p, s, res.vlog, res.n_expanded,
                                    res.n_dist)
                 return (res, stats) if introspect else res
-            return run
+            return graph
         return self.run(key, make, idx.graph, xq, xq_norm, scale, idx.xb,
                         idx.xb_norm, idx.attr, q, filt, idx.entry)
 
@@ -281,11 +288,11 @@ class Executor:
         key = ("unfiltered", "default", "f32", k, ls, max_iters, None)
 
         def make():
-            def run(graph, xb, xb_norm, attr, q, entry):
+            def unfiltered(graph, xb, xb_norm, attr, q, entry):
                 return greedy_search(graph, xb, xb_norm, attr, q, entry,
                                      unfiltered_key_fn(), ls=ls, k=k,
                                      max_iters=max_iters)
-            return run
+            return unfiltered
         return self.run(key, make, idx.graph, idx.xb, idx.xb_norm, idx.attr,
                         jnp.asarray(queries), idx.entry)
 
@@ -306,7 +313,7 @@ class Executor:
         regroups routes).
         """
         def make():
-            def run(xb, attr, q, filt):
+            def scan(xb, attr, q, filt):
                 gt = exact_filtered_knn(xb, attr, q, filt, k=k, block=block,
                                         use_kernel=use_kernel)
                 B = q.shape[0]
@@ -316,7 +323,8 @@ class Executor:
                 return SearchResult(ids, prim, gt.d2,
                                     jnp.zeros((B, 0), jnp.int32),
                                     jnp.zeros((B,), jnp.int32), gt.n_dist)
-            return run
+            scan.__name__ = key[0]          # the program: jit_<route>
+            return scan
         return self.run(key, make, xb, attr, jnp.asarray(queries), filt)
 
     def _reorder_compound(self, filt):
@@ -343,7 +351,8 @@ class Executor:
                          filt, self.index.attr, ids)
         # [L, B, S] -> per-leaf sample vectors pooled over the query batch
         # (clause order is static for the whole batch, like the old median)
-        v = np.asarray(valid)
+        with span("sync:reorder"):
+            v = np.asarray(valid)
         return reorder_clauses(filt, list(v.reshape(v.shape[0], -1)))
 
     def prefilter(self, queries, filt, *, k: int,
@@ -409,7 +418,12 @@ class Executor:
         """
         from .dispatch import merge_topk
         key = ("merge", "default", "f32", k, 0, 0, None)
-        return self.run(key, lambda: partial(merge_topk, k=k), base, extra)
+
+        def make():
+            def merge(base, extra):
+                return merge_topk(base, extra, k=k)
+            return merge
+        return self.run(key, make, base, extra)
 
     # -- postfilter route (oversampled unfiltered beam + filter) -----------
     def postfilter(self, queries, filt, *, k: int, ls: int,
@@ -428,7 +442,7 @@ class Executor:
         key = ("postfilter", "default", "f32", k, ls, max_iters, filt.kind)
 
         def make():
-            def run(graph, xb, xb_norm, attr, q, filt, entry):
+            def postfilter(graph, xb, xb_norm, attr, q, filt, entry):
                 res = greedy_search(graph, xb, xb_norm, attr, q, entry,
                                     unfiltered_key_fn(), ls=ls, k=ls,
                                     max_iters=max_iters)
@@ -443,6 +457,6 @@ class Executor:
                                               dtype=jnp.int32)
                 return SearchResult(idsm[:, :k], prim[:, :k], sec[:, :k],
                                     res.vlog, res.n_expanded, n_dist)
-            return run
+            return postfilter
         return self.run(key, make, idx.graph, idx.xb, idx.xb_norm, idx.attr,
                         jnp.asarray(queries), filt, idx.entry)

@@ -68,6 +68,7 @@ import numpy as np
 from ..core.filters import (AttrTable, FilterBatch, FilterExpr, Leaf, And,
                             Or, Not, _broadcast_rows, describe, matches,
                             matches_sampled)
+from ..obs.spans import span
 
 ROUTES = ("prefilter", "graph", "postfilter")
 
@@ -358,18 +359,22 @@ def _route_of(sel: float, cfg: PlannerConfig, router) -> str:
 def _estimate(filt, table: AttrTable, cfg: PlannerConfig,
               executor) -> Tuple[np.ndarray, int]:
     """Shared probe: host f32[B] estimates + the probe size used."""
-    if executor is not None:
-        ids = executor.sample_ids(table.n, cfg.n_samples, cfg.seed)
-    else:
-        ids = sample_ids(table.n, cfg.n_samples, cfg.seed)
-    n_sampled = int(ids.shape[0])
-    if executor is not None:
-        key = ("estimate", "default", "f32", 0, 0, 0, filt.kind, n_sampled)
-        est = executor.run(key, lambda: estimate_selectivity,
-                           filt, table, ids)
-    else:
-        est = estimate_selectivity(filt, table, ids)
-    return np.asarray(est, np.float32), n_sampled
+    with span("plan.probe"):
+        if executor is not None:
+            ids = executor.sample_ids(table.n, cfg.n_samples, cfg.seed)
+        else:
+            ids = sample_ids(table.n, cfg.n_samples, cfg.seed)
+        n_sampled = int(ids.shape[0])
+        if executor is not None:
+            key = ("estimate", "default", "f32", 0, 0, 0, filt.kind,
+                   n_sampled)
+            est = executor.run(key, lambda: estimate_selectivity,
+                               filt, table, ids)
+        else:
+            est = estimate_selectivity(filt, table, ids)
+        with span("sync:planner"):
+            est = np.asarray(est, np.float32)
+    return est, n_sampled
 
 
 def plan(filt, table: AttrTable,
@@ -405,15 +410,16 @@ def plan_per_query(filt, table: AttrTable,
     cost instead of the static thresholds.
     """
     sel, n_sampled = _estimate(filt, table, cfg, executor)
-    routes = tuple(_route_of(float(s), cfg, router) for s in sel)
-    routes_arr = np.asarray(routes)
-    groups = []
-    for route in ROUTES:
-        members = np.flatnonzero(routes_arr == route)
-        if members.size:
-            groups.append(GroupPlan(route, members.astype(np.int32),
-                                    float(np.median(sel[members]))))
-    batch_sel = float(np.median(sel))
+    with span("plan.band"):
+        routes = tuple(_route_of(float(s), cfg, router) for s in sel)
+        routes_arr = np.asarray(routes)
+        groups = []
+        for route in ROUTES:
+            members = np.flatnonzero(routes_arr == route)
+            if members.size:
+                groups.append(GroupPlan(route, members.astype(np.int32),
+                                        float(np.median(sel[members]))))
+        batch_sel = float(np.median(sel))
     if router is None:
         return PerQueryPlan(routes, sel, tuple(groups), n_sampled)
     return PerQueryPlan(routes, sel, tuple(groups), n_sampled,

@@ -162,6 +162,7 @@ class ShardedExecutor(Executor):
                 gids = jnp.where(res.ids >= 0, res.ids + sid * n_loc, -1)
                 return _merge_across_shards(res._replace(ids=gids), k=k,
                                             n_shards=S)
+            shard_fn.__name__ = key[0]      # the program: jit_<route>
             return jax.shard_map(
                 shard_fn, mesh=mesh,
                 in_specs=(P("data"),) * len(db_args) + (P(), P()),

@@ -32,6 +32,7 @@ restarted server resumes mid-stream bit-for-bit.
 """
 from __future__ import annotations
 
+from contextlib import nullcontext
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
@@ -42,6 +43,7 @@ from ..core.build import finalize_graph, make_insert_step
 from ..core.distances import sq_norms
 from ..core.filters import AttrTable, as_filter
 from ..core.jag import JAGConfig, JAGIndex
+from ..obs.spans import span
 from .delta import DeltaSegment
 
 
@@ -265,17 +267,33 @@ class StreamingJAGIndex:
                 f"{int(base.graph.shape[1])} (legacy archive loaded with "
                 f"default BuildConfig?) — cannot compact; rebuild the base "
                 f"index or save a modern archive")
-        xv, dattr = self.delta.device()
-        xb_new = jnp.concatenate([jnp.asarray(base.xb), xv], axis=0)
-        attr_new = base.attr.append(dattr)
-        xb_norm = sq_norms(xb_new)
-        n0, m = int(base.xb.shape[0]), self.delta.n
-        graph = jnp.concatenate(
-            [base.graph,
-             jnp.full((m, bcfg.row_width), -1, jnp.int32)], axis=0)
-        degree = jnp.concatenate(
-            [jnp.asarray(base.degree, jnp.int32),
-             jnp.zeros((m,), jnp.int32)], axis=0)
+        with span("compact", rows=self.delta.n):
+            self.base = self._fold(verbose)
+        self.delta.reset()
+        self._merged = None
+        self.epoch += 1
+        self.n_compactions += 1
+        if self.telemetry is not None and self.telemetry.enabled:
+            self.telemetry.on_compaction()
+        return True
+
+    def _fold(self, verbose: bool) -> JAGIndex:
+        """The compacted base: the delta rows appended, every insert pass
+        over their ids, the overflow re-prune, the f32 layout extended."""
+        base = self.base
+        bcfg = base.build_cfg
+        with span("compact.prepare"):
+            xv, dattr = self.delta.device()
+            xb_new = jnp.concatenate([jnp.asarray(base.xb), xv], axis=0)
+            attr_new = base.attr.append(dattr)
+            xb_norm = sq_norms(xb_new)
+            n0, m = int(base.xb.shape[0]), self.delta.n
+            graph = jnp.concatenate(
+                [base.graph,
+                 jnp.full((m, bcfg.row_width), -1, jnp.int32)], axis=0)
+            degree = jnp.concatenate(
+                [jnp.asarray(base.degree, jnp.int32),
+                 jnp.zeros((m,), jnp.int32)], axis=0)
         insert = make_insert_step(bcfg)
         bsz = bcfg.batch_size
         new_ids = np.arange(n0, n0 + m, dtype=np.int64)
@@ -285,28 +303,27 @@ class StreamingJAGIndex:
                 ids = new_ids[i * bsz:(i + 1) * bsz]
                 if len(ids) < bsz:  # pad final batch cyclically (dup-safe)
                     ids = np.resize(ids, bsz)
-                graph, degree = insert(graph, degree, xb_new, xb_norm,
-                                       attr_new, jnp.asarray(ids, jnp.int32),
-                                       base.entry)
+                # the first call traces the insert program made above
+                first = pass_i == 0 and i == 0
+                with span("compact.insert", **{"pass": pass_i, "batch": i}), \
+                        (span("jit:insert") if first else nullcontext()):
+                    graph, degree = insert(graph, degree, xb_new, xb_norm,
+                                           attr_new,
+                                           jnp.asarray(ids, jnp.int32),
+                                           base.entry)
                 if verbose:
                     print(f"  compaction pass {pass_i + 1}/{bcfg.n_passes} "
                           f"batch {i + 1}/{n_batches}")
-            graph, degree = finalize_graph(graph, degree, xb_new, xb_norm,
-                                           attr_new, bcfg)
+            with span("compact.finalize"):
+                graph, degree = finalize_graph(graph, degree, xb_new,
+                                               xb_norm, attr_new, bcfg)
         new_base = JAGIndex(xb_new, attr_new, graph, degree, base.entry,
                             base.cfg, bcfg)
         if "f32" in base._fused:
             from ..serve.layout import extend_layout
             new_base._fused["f32"] = extend_layout(base._fused["f32"],
                                                    xv, dattr)
-        self.base = new_base
-        self.delta.reset()
-        self._merged = None
-        self.epoch += 1
-        self.n_compactions += 1
-        if self.telemetry is not None and self.telemetry.enabled:
-            self.telemetry.on_compaction()
-        return True
+        return new_base
 
     # -- queries (base route + delta scan, merged exactly) -----------------
     def _spans(self):
@@ -318,7 +335,6 @@ class StreamingJAGIndex:
 
     def _with_delta(self, base_res: SearchResult, queries,
                     filt, k: int) -> SearchResult:
-        from contextlib import nullcontext
         if self.telemetry is not None and self.telemetry.enabled:
             self.telemetry.on_search(delta_scanned=self.delta.n > 0)
         if self.delta.n == 0:
@@ -327,11 +343,10 @@ class StreamingJAGIndex:
         be = self.compaction_break_even(k)
         if be is not None:          # telemetry: predicted tax actually paid
             self.delta_tax_us += be[0] * int(np.shape(queries)[0])
-        spans = self._spans()
-        with (spans.span("delta", rows=self.delta.n) if spans is not None
-              else nullcontext()):
+        rec = self._spans()
+        with span("delta", rec, rows=self.delta.n):
             extra = self.executor.delta(queries, filt, k=k)
-        with (spans.span("merge") if spans is not None else nullcontext()):
+        with span("merge", rec):
             return self.executor.merge(base_res, extra, k=k)
 
     def search(self, queries, filt, k: int = 10, ls: int = 64,

@@ -197,6 +197,34 @@ def test_jag006_telemetry_in_jit_roots():
     assert codes(timer, surface) == ["JAG006"]
 
 
+def test_jag006_spans_in_jit_roots():
+    # a span inside a traced function times tracing, not execution
+    factory = """
+    def make():
+        def run(x):
+            with span("plan"):
+                return x
+        return run
+    """
+    assert codes(factory) == ["JAG006"]
+    annotated = """
+    import jax
+
+    @jax.jit
+    def f(x):
+        with jax.profiler.TraceAnnotation("jag.x"):
+            return x + 1
+    """
+    assert codes(annotated, "src/repro/core/build.py") == ["JAG006"]
+    # around the compiled call, on the host, a span is the contract
+    host = """
+    def launch(fn, x):
+        with span("execute:graph", rec, queries=4):
+            return fn(x)
+    """
+    assert codes(host) == []
+
+
 def test_jag006_host_side_telemetry_is_fine():
     # the actual dispatch/search_auto wrapper shape: timing + recording
     # around (not inside) the compiled route

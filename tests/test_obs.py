@@ -29,7 +29,7 @@ from repro.obs.recal import (heldout_error, observations_from_traces,
 from repro.obs.shadow import (ShadowAuditor, cells_from_records,
                               load_shadow_jsonl, sampled_qid, sel_band,
                               wilson_interval)
-from repro.obs.spans import SpanRecorder
+from repro.obs.spans import SpanRecorder, span
 from repro.obs.trace import TraceBuffer, TraceRecord, load_buffer, load_jsonl
 from repro.serve.planner import PlannerConfig, explain
 from repro.stream import StreamingJAGIndex
@@ -790,7 +790,7 @@ def test_introspect_traces_stamped_and_summarized(setup):
 # pipeline spans (tentpole)
 # ---------------------------------------------------------------------------
 
-def test_span_recorder_nesting_and_chrome_export(tmp_path):
+def test_span_recorder_nesting_and_chrome_export():
     sr = SpanRecorder()
     with sr.span("outer", batch=2):
         with sr.span("inner"):
@@ -808,15 +808,16 @@ def test_span_recorder_nesting_and_chrome_export(tmp_path):
         assert by_name[child].t1 <= by_name["outer"].t1
     totals = sr.totals_us()
     assert totals["outer"] >= totals["inner"] + totals["inner2"] - 1e-6
-    path = str(tmp_path / "trace.json")
-    assert sr.export_chrome_trace(path) == 3
-    doc = json.load(open(path))
-    events = doc["traceEvents"]
-    assert all(e["ph"] == "X" and e["cat"] == "serve" for e in events)
-    assert all(e["dur"] >= 0 for e in events)
-    ev = {e["name"]: e for e in events}
-    assert ev["inner"]["args"]["parent"] == "outer"
-    assert ev["outer"]["args"]["batch"] == 2
+    assert by_name["outer"].args == {"batch": 2}
+    assert all(s.duration_us >= 0 for s in sr.spans)
+    # span() records in a recorder it is given, under the same name, and
+    # nests with the recorder's own spans
+    with sr.span("top"):
+        with span("leaf", sr, rows=3):
+            pass
+    leaf = sr.spans[-2]
+    assert (leaf.name, leaf.parent, leaf.depth) == ("leaf", "top", 1)
+    assert leaf.args == {"rows": 3}
 
 
 def test_span_recorder_bounded():
@@ -862,6 +863,145 @@ def test_streaming_spans_cover_delta_and_merge(setup):
     assert "delta" in names and "merge" in names
     (delta_span,) = [s for s in tel.spans.spans if s.name == "delta"]
     assert delta_span.args.get("rows") == 16
+
+
+def _profiled(trace_dir, fn):
+    """Run ``fn`` under ``jax.profiler.trace``; returns (fn's result, the
+    ``jag.*`` host spans [(name, start_ns, end_ns, args)] in start order)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(trace_dir)):
+        out = fn()
+    (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"),
+                        recursive=True)
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            spans += [(e.name[len("jag."):], e.start_ns,
+                       e.start_ns + e.duration_ns, dict(e.stats))
+                      for line in plane.lines for e in line.events
+                      if e.name.startswith("jag.")]
+    return out, sorted(spans, key=lambda e: (e[1], -e[2]))
+
+
+def _inside(spans, outer):
+    return [e for e in spans if outer[1] <= e[1] and e[2] <= outer[2]
+            and e is not outer]
+
+
+def test_search_auto_spans_on_the_profiler_trace(setup, tmp_path):
+    """With no telemetry attached, a search writes its spans into the
+    profiler trace: one ``jag.search_auto`` per request, with the planner,
+    gather, execute and scatter stages inside it; the programs compile in
+    the first request only (``jag.jit:*``)."""
+    index, q = setup
+
+    def two():          # k and ls no other test compiles
+        return [index.search_auto(q, mixed_filt(), k=4, ls=10)
+                for _ in range(2)]
+    _, spans = _profiled(tmp_path, two)
+    tops = [e for e in spans if e[0] == "search_auto"]
+    assert len(tops) == 2
+    first, second = (e[3]["request"] for e in tops)
+    assert second == first + 1
+    assert first == index.executor.n_requests - 1
+    names = [e[0] for e in _inside(spans, tops[0])]
+    for want in ("plan", "plan.probe", "sync:planner", "plan.band",
+                 "scatter"):
+        assert want in names
+    for prefix in ("gather:", "execute:", "jit:"):
+        assert any(n.startswith(prefix) for n in names), prefix
+    assert {n for n in names if n.startswith("execute:")} == {
+        "execute:prefilter", "execute:postfilter"}
+    assert not [n for n in (e[0] for e in _inside(spans, tops[1]))
+                if n.startswith("jit:")]
+
+
+def test_compact_spans_on_the_profiler_trace(setup, tmp_path):
+    """A compaction writes ``jag.compact`` holding one ``compact.insert``
+    per pass and batch, at most one ``jit:insert`` (the call that traces
+    the insert program, pass 0 batch 0), and ``finalize_graph``'s degree
+    reads and re-prunes."""
+    index, _ = setup
+    stream = StreamingJAGIndex(index, compact_frac=10.0)
+    rng = np.random.default_rng(9)
+    m = 200
+    stream.insert(rng.normal(size=(m, D)).astype(np.float32),
+                  range_table(rng.uniform(0, 1, m).astype(np.float32)))
+    bcfg = index.build_cfg
+    n_batches = -(-m // bcfg.batch_size)
+    assert n_batches == 2
+    _, spans = _profiled(tmp_path, stream.compact)
+    (top,) = [e for e in spans if e[0] == "compact"]
+    assert top[3]["rows"] == m
+    inner = _inside(spans, top)
+    names = [e[0] for e in inner]
+    assert set(names) <= {"compact.prepare", "compact.insert", "jit:insert",
+                          "compact.finalize", "compact.reprune",
+                          "sync:finalize", "jit:reprune"}
+    assert names.count("compact.prepare") == 1
+    inserts = [e for e in inner if e[0] == "compact.insert"]
+    assert [(e[3]["pass"], e[3]["batch"]) for e in inserts] == [
+        (p, i) for p in range(bcfg.n_passes) for i in range(n_batches)]
+    jits = [e for e in inner if e[0] == "jit:insert"]
+    assert len(jits) <= 1
+    assert all(j in _inside(spans, inserts[0]) for j in jits)
+    assert names.count("compact.finalize") == bcfg.n_passes
+    # each finalize reads the degrees at least once, and re-prunes only
+    # between reads
+    assert names.count("sync:finalize") >= bcfg.n_passes
+    assert names.count("compact.reprune") <= names.count("sync:finalize")
+    assert names.count("jit:reprune") <= bcfg.n_passes
+
+
+def _route_calls(index, stream, q):
+    """Each executor route, called once (``route -> thunk``)."""
+    ex, sx = index.executor, stream.executor
+    f = uniform_filt(0.4)
+    res = index.search(q, f, k=3, ls=8)
+    return {
+        "prefilter": lambda: ex.prefilter(q, f, k=3),
+        "graph": lambda: ex.graph(q, f, k=3, ls=8, max_iters=16),
+        "graph[fused]": lambda: ex.graph(q, f, k=3, ls=8, max_iters=16,
+                                         layout="fused"),
+        "graph[int8]": lambda: ex.graph(q, f, k=3, ls=8, max_iters=16,
+                                        dtype="int8"),
+        "graph[fused,int8]": lambda: ex.graph(q, f, k=3, ls=8, max_iters=16,
+                                              layout="fused", dtype="int8"),
+        "postfilter": lambda: ex.postfilter(q, f, k=3, ls=8, max_iters=16),
+        "unfiltered": lambda: ex.unfiltered(q, k=3, ls=8, max_iters=16),
+        "delta": lambda: sx.delta(q, f, k=3),
+        "merge": lambda: ex.merge(res, res, k=3),
+    }
+
+
+@pytest.mark.parametrize("variant", [
+    "prefilter", "graph", "graph[fused]", "graph[int8]", "graph[fused,int8]",
+    "postfilter", "unfiltered", "delta", "merge"])
+def test_route_programs_are_named_for_their_route(setup, variant):
+    """Every executor route compiles as the program ``jit_<route>``, so a
+    device trace names the route without the launch log."""
+    import jax
+    index, q = setup
+    stream = StreamingJAGIndex(index, compact_frac=10.0)
+    rng = np.random.default_rng(3)
+    stream.insert(rng.normal(size=(8, D)).astype(np.float32),
+                  range_table(rng.uniform(0, 1, 8).astype(np.float32)))
+    calls = _route_calls(index, stream, q)
+    ex = stream.executor if variant == "delta" else index.executor
+    ex.trace_log = []
+    try:
+        calls[variant]()
+        (key, make, args), = [t for t in ex.trace_log
+                              if t[0][0] not in ("leafval", "estimate")]
+    finally:
+        ex.trace_log = None
+    route = variant.split("[")[0]
+    assert key[0] == route
+    text = jax.jit(make()).lower(*args).as_text()
+    assert text.startswith(f"module @jit_{route} ")
 
 
 # ---------------------------------------------------------------------------
