@@ -7,9 +7,12 @@ query planner (serve/planner.py) routes low-selectivity batches here, and
 the executor (serve/executor.py) adapts the result to the SearchResult
 contract. ``use_kernel=True`` swaps the per-block distance matmul for the
 scalar-prefetch Pallas tile scan (kernels/ops.gather_dist_tile, padded once
-up front) so each database block is DMA'd HBM->VMEM once on TPU. The block
-defaults to ``kernels.gather_dist.scan_tile(d)``: 4096 rows up to d=256,
-fewer at wider rows, so a double-buffered tile fits the chip's VMEM.
+up front): one kernel call per database block, which DMAs the block
+HBM->VMEM once and scores it against the whole query group in fixed
+128-row query blocks, so each query's distances are the same whatever
+group it is served in. The block defaults to
+``kernels.gather_dist.scan_tile(d)``: 4096 rows up to d=256, fewer at
+wider rows, so a double-buffered tile fits the chip's VMEM.
 """
 from __future__ import annotations
 
@@ -70,8 +73,8 @@ def exact_filtered_knn(xb, attr: AttrTable, queries, filt,
         idc = jnp.minimum(ids, N - 1)
         if use_kernel:
             from ..kernels import ops
-            d2 = ops.gather_dist_tile(xb_pad, jnp.full((B,), bi, jnp.int32),
-                                      q_pad, tile=block)  # [B, blk]
+            d2 = ops.gather_dist_tile(xb_pad, bi, q_pad,
+                                      tile=block)            # [B, blk]
         else:
             xbl = jnp.take(xb32, idc, axis=0)                # [blk, d]
             d2 = (jnp.take(xn, idc)[None, :] + qn[:, None]

@@ -78,20 +78,20 @@ def fused_expand(packed, ids, q, q_norm, *, d: int,
                             interpret=_interp(interpret))
 
 
-def gather_dist_tile(xb, base, q, *, tile: int,
+def gather_dist_tile(xb, bi, q, *, tile: int,
                      interpret: bool | None = None) -> jnp.ndarray:
-    """Contiguous-tile fused gather+distance: lane b scores database rows
-    ``[base[b]*tile, (base[b]+1)*tile)`` against q[b] -> f32 [B, tile].
+    """One database tile scored against the whole query group: rows
+    ``[bi*tile, (bi+1)*tile)`` of xb against every q[b] -> f32 [B, tile].
 
-    Besides the sorted/bucketed build layouts, this is the prefilter
-    route's masked-scan inner loop (core/ground_truth.py with
-    ``use_kernel=True``): the blocked exact scan DMAs each database tile
-    HBM->VMEM once per grid step. xb's row count must be a tile multiple
-    and d an 8-lane multiple — callers pad once up front (padded rows score
-    against the zero vector and must be masked; ``exact_filtered_knn``'s
-    ``inb`` mask does).
+    The prefilter route's masked-scan inner loop (core/ground_truth.py with
+    ``use_kernel=True``): each call DMAs its tile HBM->VMEM once and does
+    one ``[bq, d] x [d, tile]`` MXU product per fixed query block, so a
+    query's row is bit-identical whatever group it is scored in. xb's row
+    count must be a tile multiple and d an 8-lane multiple — callers pad
+    once up front (padded rows score against the zero vector and must be
+    masked; ``exact_filtered_knn``'s ``inb`` mask does).
     """
-    return _gd.gather_dist_tile(jnp.asarray(xb), jnp.asarray(base, jnp.int32),
+    return _gd.gather_dist_tile(jnp.asarray(xb), jnp.asarray(bi, jnp.int32),
                                 jnp.asarray(q), tile=tile,
                                 interpret=_interp(interpret))
 

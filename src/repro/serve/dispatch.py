@@ -29,9 +29,12 @@ distance computations (every gathered candidate dot goes through
 ``distances.gathered_dot``), so group composition never leaks into a
 query's lane. One caveat: the prefilter scan's block distances are a
 ``[B, d] @ [d, block]`` GEMM (a batch-invariant mul+sum there measures
-~70x slower) — row-invariant on CPU (measured) and per-row by
-construction in the TPU tile kernel, but an untested GPU GEMM could in
-principle tile low-order float bits differently per batch size.
+~70x slower) — row-invariant on CPU (measured), and per-row by
+construction in the TPU tile kernel, which DMAs each block once per call
+and runs one fixed 128-row query block per product (groups under 128 rows
+take one 8-aligned block), so a row's bits do not depend on its group;
+an untested GPU GEMM could in principle tile low-order float bits
+differently per batch size.
 """
 from __future__ import annotations
 

@@ -50,18 +50,40 @@ def test_gather_dist_matches_ref(B, C, N, d):
                                rtol=1e-5, atol=1e-4)
 
 
-def test_gather_dist_tile():
+@pytest.mark.parametrize("B,N,d,tile", [
+    (8, 256, 32, 64),
+    (170, 8192, 104, 4096),    # range-mixed's prefilter group, 2 blocks
+    (1, 256, 104, 64),         # one query: one 8-row block
+    (60, 120, 104, 60),        # the delta route's short unaligned block
+])
+def test_gather_dist_tile(B, N, d, tile):
     rng = np.random.default_rng(3)
-    N, d, tile, B = 256, 32, 64, 8
-    xb = jnp.asarray(rng.normal(size=(N, d)), jnp.float32)
-    q = jnp.asarray(rng.normal(size=(B, d)), jnp.float32)
-    base = jnp.asarray(rng.integers(0, N // tile, B), jnp.int32)
-    got = gather_dist_tile(xb, base, q, tile=tile, interpret=True)
-    for b in range(B):
-        rows = xb[int(base[b]) * tile:(int(base[b]) + 1) * tile]
-        want = ((rows - q[b]) ** 2).sum(-1)
-        np.testing.assert_allclose(np.asarray(got[b]), np.asarray(want),
+    xb = rng.normal(size=(N, d)).astype(np.float32)
+    q = rng.normal(size=(B, d)).astype(np.float32)
+    x64, q64 = xb.astype(np.float64), q.astype(np.float64)
+    for bi in sorted({0, N // tile - 1}):
+        got = gather_dist_tile(jnp.asarray(xb), jnp.int32(bi), jnp.asarray(q),
+                               tile=tile, interpret=True)
+        assert got.shape == (B, tile) and got.dtype == jnp.float32
+        rows = x64[bi * tile:(bi + 1) * tile]
+        want = ((q64[:, None, :] - rows[None]) ** 2).sum(-1)
+        np.testing.assert_allclose(np.asarray(got), want,
                                    rtol=1e-5, atol=1e-4)
+
+
+def test_gather_dist_tile_row_independent_of_group():
+    """A query's row is bit-identical scored alone or in a group of 170:
+    the per-row property serve/dispatch.py documents."""
+    rng = np.random.default_rng(5)
+    N, d, tile = 512, 104, 256
+    xb = jnp.asarray(rng.normal(size=(N, d)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(170, d)), jnp.float32)
+    group = np.asarray(gather_dist_tile(xb, jnp.int32(1), q, tile=tile,
+                                        interpret=True))
+    for b in (0, 127, 128, 169):
+        alone = np.asarray(gather_dist_tile(xb, jnp.int32(1), q[b:b + 1],
+                                            tile=tile, interpret=True))
+        np.testing.assert_array_equal(alone[0], group[b])
 
 
 @pytest.mark.parametrize("B,N,W", [(8, 16, 1), (64, 128, 4), (33, 77, 7)])

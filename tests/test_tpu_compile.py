@@ -60,15 +60,18 @@ def _compile(fn, *args, **kw):
     return text, time.perf_counter() - t0
 
 
+@pytest.mark.parametrize("n_q", [B, 170])
 @pytest.mark.parametrize("d", [104, 128, 768])
-def test_gather_dist_tile_compiles(one_chip, d):
+def test_gather_dist_tile_compiles(one_chip, d, n_q):
+    """One tile against a whole query group: the module's batch and the
+    range-mixed cell's prefilter group of 170, padded to 128-row blocks."""
     tile = scan_tile(d)
     assert tile == (1024 if d == 768 else 4096)
     text, _ = _compile(
         gather_dist.gather_dist_tile,
         _spec(one_chip, (N_SCAN, d), jnp.float32),
-        _spec(one_chip, (B,), jnp.int32),
-        _spec(one_chip, (B, d), jnp.float32), tile=tile)
+        _spec(one_chip, (), jnp.int32),
+        _spec(one_chip, (n_q, d), jnp.float32), tile=tile)
     assert "tpu_custom_call" in text
 
 
